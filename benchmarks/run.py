@@ -9,7 +9,6 @@ Emits ``name,value,derived`` CSV rows:
 from __future__ import annotations
 
 import argparse
-import sys
 
 
 def main() -> None:
@@ -21,6 +20,9 @@ def main() -> None:
                     help="skip the slot-vs-paged serving A/B (the slowest "
                          "family: drains mixed traffic through two engines)")
     args = ap.parse_args()
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks.figures import ALL_FIGURES
     from benchmarks.roofline import roofline_rows
@@ -37,10 +39,7 @@ def main() -> None:
 
     for fig in ALL_FIGURES:
         emit(fig())
-    try:
-        emit(roofline_rows())
-    except Exception as e:                                    # noqa: BLE001
-        print(f"roofline/error,0,{e!r}", file=sys.stderr)
+    emit(roofline_rows())
     if not args.skip_micro:
         for micro in ALL_MICRO:
             emit(micro())

@@ -1009,11 +1009,16 @@ print("RESULT " + json.dumps(out))
 
 
 def _sharded_results(tiny: bool) -> Dict[str, Any]:
-    """Mesh 1 vs mesh 2 on identical traffic, in a 2-device subprocess."""
+    """Mesh 1 vs mesh 2 on identical traffic, in a 2-device subprocess.
+
+    The child measures two placeholder CPU devices, so it pins itself to
+    the CPU: on a chip host it must not contend for the chip the parent
+    holds."""
     import subprocess
     import sys
 
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=2").strip()
     src = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -1177,7 +1182,8 @@ def rows_from(results: Dict[str, Any]) -> Iterator[Row]:
            f"magnify the fixed host-side cost)")
     sh = results["sharded"]
     yield ("serving/sharded_tok_s_mesh1", sh["mesh1"]["tok_s"],
-           f"single-device ragged engine in the 2-device subprocess "
+           f"single-device ragged engine in the 2-placeholder-CPU-device "
+           f"subprocess "
            f"({sh['mesh1']['tokens']} toks over {sh['mesh1']['steps']} steps)")
     yield ("serving/sharded_tok_s_mesh2", sh["mesh2"]["tok_s"],
            "same traffic, KV-head-sharded pool + shard_map step at mesh 2")

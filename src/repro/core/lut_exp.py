@@ -8,9 +8,8 @@ error < 0.54%) or ``1 + r`` (order 1, error < 0.0015%).
 
 TPU adaptation: ``2^n`` is an exact exponent-field bit-twiddle, the table lives in
 VMEM (one 128-lane VREG row — K=128 is exactly the TPU lane width), and the lookup
-is a gather.  The Pallas kernel (``repro.kernels.lut_exp``) performs the gather as a
-one-hot × table matmul on the MXU — the same unit that executes the MVMs, which is
-the UCLM "unified compute and lookup" property.
+is a gather.  The Pallas kernels (``repro.kernels.lut_exp``) perform it as an exact
+select over the 128 table entries, which Mosaic lowers.
 
 This module is the pure-jnp shared math: both the kernel and the reference oracle
 import from here, so there is a single source of truth for the decomposition.
@@ -80,8 +79,8 @@ def lut_exp(x: jax.Array, *, k: int = K, order: int = 1,
             table: jax.Array | None = None) -> jax.Array:
     """LUT exponential, pure-jnp path (the oracle; used by the model code on CPU).
 
-    The Pallas kernel in ``repro.kernels.lut_exp`` computes the same function with
-    the table lookup performed as a one-hot MXU matmul.
+    The Pallas kernel in ``repro.kernels.lut_exp`` computes the same function,
+    bit for bit, with the table lookup performed as a select.
     """
     dtype = x.dtype
     if table is None:

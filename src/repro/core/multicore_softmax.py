@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.lut_exp import lut_exp
-from repro.parallel.compat import axis_size
 
 
 def tree_allreduce(x: jax.Array, op: Callable, axis_name: str) -> jax.Array:
@@ -33,7 +32,7 @@ def tree_allreduce(x: jax.Array, op: Callable, axis_name: str) -> jax.Array:
     O(log₂ n) rounds; after round i every device holds the reduction over its
     2^(i+1)-device group.  Requires the axis size to be a power of two.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     assert n & (n - 1) == 0, f"tree_allreduce needs power-of-two axis, got {n}"
     dist = 1
     while dist < n:

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.lut_exp import lut_exp
-from repro.parallel.compat import axis_size
 from repro.core.lut_softmax import NEG_INF, softcap
 from repro.core.streaming_attention import _EXP_FNS, _split_heads
 
@@ -39,7 +38,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, axis_name: str, *,
     q: (B, Hq, Lq_loc, D), k/v: (B, Hkv, Lkv_loc, D).  Device i owns global rows
     [i·Lq_loc, (i+1)·Lq_loc).  Returns the local (B, Hq, Lq_loc, D) output.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, hq, lq, d = q.shape
     hkv, lkv = k.shape[1], k.shape[2]
@@ -98,7 +97,7 @@ def distributed_decode_attention(q: jax.Array, k_cache: jax.Array,
     sharded on L.  ``kv_len`` is the *global* number of valid cache rows.
     Returns the replicated (B, Hq, 1, D) attention output.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, hq, lq, d = q.shape
     hkv, lloc = k_cache.shape[1], k_cache.shape[2]

@@ -4,8 +4,8 @@ Three kernels, each ``kernel.py`` (pl.pallas_call + BlockSpec VMEM tiling) +
 ``ops.py`` (jit'd wrapper; interpret=True off-TPU) + ``ref.py`` (pure-jnp
 oracle):
 
-- ``lut_exp``              — the UCLM LUT exponential; table lookup as a
-                             one-hot × table matmul on the MXU (paper §III).
+- ``lut_exp``              — the UCLM LUT exponential; table lookup as an
+                             exact select over the 128 entries (paper §III).
 - ``streaming_attention``  — fine-grained-pipelined flash-style attention
                              with the LUT softmax inside (paper §IV).
 - ``paged_attention``      — decode attention that reads KV pages in place

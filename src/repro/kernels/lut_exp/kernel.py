@@ -1,26 +1,19 @@
 """Pallas TPU kernel for the UCLM LUT-exponential (paper §III-A/B).
 
-The paper's UCLM performs the ``2^(d/K)`` table lookup *inside the same SRAM
-array that does the MVMs*.  The TPU-native statement of that property: the
-lookup is executed as a **one-hot × table matmul on the MXU** — the same
-systolic unit that runs the surrounding matrix products — rather than on the
-VPU or via scalar gathers.  K = 128 is exactly one TPU lane width, so the
+The paper's UCLM performs the ``2^(d/K)`` table lookup inside the same SRAM
+array that does the MVMs.  K = 128 is exactly one TPU lane width, so the
 table occupies a single (1, 128) VMEM row (one VREG row), mirroring the
 paper's "one table per 64×64 array" layout (Fig. 4a).
 
+The lookup itself is a select over the K entries on the VPU
+(:func:`table_lookup`).  Mosaic lowers neither a vector gather nor the
+lane-flattening reshape a one-hot × table MXU matmul needs, and a
+default-precision f32 matmul on the chip rounds ``T[d]`` to bf16, where a
+select copies it exactly.  Whether the LUT pays against ``jnp.exp`` on the
+chip is measured, not assumed.
+
 Blocking: the input is viewed as (M, 128) lanes; each grid step processes a
-``(block_m, 128)`` VMEM tile.  Per tile the working set is
-
-    x tile          block_m × 128 × 4 B
-    one-hot         (block_m·128) × 128 × 4 B   (MXU operand)
-    table           128 × 4 B
-
-so ``block_m = 256`` keeps the one-hot operand at 16 MiB — fits v5e VMEM
-(~128 KiB x tile + 16 MiB one-hot is too big; we therefore build the one-hot
-in ``sub`` slabs of 8 rows: 8·128×128×4 B = 512 KiB).  The slab loop is a
-``jax.lax.fori_loop`` inside the kernel, so the (M·128)×128 one-hot never
-materialises — the same "never materialise the big intermediate" discipline
-as the streaming-attention kernel.
+``(block_m, 128)`` VMEM tile.
 """
 from __future__ import annotations
 
@@ -32,9 +25,6 @@ from jax.experimental import pallas as pl
 
 from repro.core.lut_exp import K, LN2, LOG2E, UNDERFLOW_X
 
-# Rows of the input tile exponentiated per MXU one-hot matmul.
-SLAB = 8
-
 
 def _pow2_int_f32(n: jax.Array) -> jax.Array:
     """Exact 2^n by exponent-field construction (kernel-local copy)."""
@@ -43,43 +33,32 @@ def _pow2_int_f32(n: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
-def mxu_table_lookup(d_i: jax.Array, table: jax.Array,
-                     slab: int = SLAB) -> jax.Array:
-    """T[d] for a 2D int32 index block, as one-hot × table MXU matmuls.
+def table_lookup(d_i: jax.Array, table: jax.Array) -> jax.Array:
+    """Exactly ``T[d]`` for an int32 index block of any 2D shape.
 
-    This is the UCLM property: the lookup runs on the matmul fabric.  The
-    one-hot is built ``slab`` rows at a time so it never exceeds
-    slab·cols×K×4 B of VMEM.  Shared by the lut_exp and streaming-attention
-    kernels.
+    A select over the K table entries: ``out = where(d == k, T[k], out)``.
+    Each ``T[k]`` is a static (1, 1) lane slice broadcast against the block,
+    so the lowering needs no gather, no dynamic slice of a value and no
+    reshape across lanes — the constructs Mosaic refuses.  A select copies
+    the entry bit for bit, where a default-precision one-hot matmul on the
+    MXU would round it to bf16.  Shared by all three LUT kernels.
     """
-    rows, cols = d_i.shape
-    table = table.reshape(K, 1)
-    if rows % slab:
-        slab = 1
-
-    def slab_body(i, looked):
-        d_slab = jax.lax.dynamic_slice(d_i, (i * slab, 0), (slab, cols))
-        flat = d_slab.reshape(slab * cols)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (slab * cols, K), 1)
-        onehot = (flat[:, None] == iota).astype(jnp.float32)
-        vals = jax.lax.dot_general(
-            onehot, table, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).reshape(slab, cols)
-        return jax.lax.dynamic_update_slice(looked, vals, (i * slab, 0))
-
-    return jax.lax.fori_loop(
-        0, rows // slab, slab_body, jnp.zeros((rows, cols), jnp.float32))
+    table = table.reshape(1, K).astype(jnp.float32)
+    out = jnp.zeros(d_i.shape, jnp.float32)
+    for k in range(K):
+        out = jnp.where(d_i == k, table[:, k:k + 1], out)
+    return out
 
 
-def lut_exp_block(x: jax.Array, table: jax.Array, *, order: int = 1,
-                  slab: int = SLAB) -> jax.Array:
+def lut_exp_block(x: jax.Array, table: jax.Array, *,
+                  order: int = 1) -> jax.Array:
     """e^x for a 2D f32 block — the kernel-side LUT-exp decomposition."""
     t = x * LOG2E
     n = jnp.floor(t)
     fk = (t - n) * K
     d = jnp.clip(jnp.floor(fk), 0.0, float(K - 1))
     r = fk - d
-    looked = mxu_table_lookup(d.astype(jnp.int32), table, slab)
+    looked = table_lookup(d.astype(jnp.int32), table)
     corr = 1.0 if order == 0 else 1.0 + r * (LN2 / K)
     out = _pow2_int_f32(n) * looked * corr
     return jnp.where(x < UNDERFLOW_X, 0.0, out)

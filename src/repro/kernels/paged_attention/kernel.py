@@ -34,8 +34,8 @@ and dequantises inside the step, so quantised serving keeps its 2×-smaller
 resident cache *and* the in-place read path.
 
 Like the streaming kernel, the exponential is the paper's LUT decomposition
-(``lut_exp_block``) so softmax runs on the MXU.  VMEM per step is one page
-tile + the (G·Lq, page_size) logits + the carry — KiBs, far under budget.
+(``lut_exp_block``).  VMEM per step is one page tile + the (G·Lq,
+page_size) logits + the carry — KiBs, far under budget.
 """
 from __future__ import annotations
 
@@ -145,11 +145,17 @@ def paged_attention_4d(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     p = page_table.shape[1]
     assert rows == group * q_len, (rows, group, q_len)
     quantized = k_scale is not None
-    if not quantized:
+    if quantized:
+        # (N, Hkv, 1, ps): the scale block (1, ps) spans the last two dims
+        # in full, which TPU tiling requires; a (1, ps) block of (Hkv, ps)
+        # would not be.
+        k_scale = k_scale.reshape(n, hkv, 1, ps)
+        v_scale = v_scale.reshape(n, hkv, 1, ps)
+    else:
         # Uniform kernel arity: dummy 1-page scale pools, never dereferenced
         # (the index map pins them to page 0 and `quantized` elides the load).
-        k_scale = jnp.ones((1, hkv, ps), jnp.float32)
-        v_scale = jnp.ones((1, hkv, ps), jnp.float32)
+        k_scale = jnp.ones((1, hkv, 1, ps), jnp.float32)
+        v_scale = jnp.ones((1, hkv, 1, ps), jnp.float32)
 
     kernel = functools.partial(
         paged_attention_kernel, scale=scale, cap=cap, window=window,
@@ -162,7 +168,7 @@ def paged_attention_4d(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 
     def scale_map(b_, h, j, tbl, lens):
         del lens
-        return ((tbl[b_, j], h, 0) if quantized else (0, h, 0))
+        return ((tbl[b_, j], h, 0, 0) if quantized else (0, h, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # page table + per-lane lengths
@@ -172,8 +178,8 @@ def paged_attention_4d(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                          lambda b_, h, j, tbl, lens: (b_, h, 0, 0)),
             pl.BlockSpec((None, None, ps, d), page_map),
             pl.BlockSpec((None, None, ps, dv), page_map),
-            pl.BlockSpec((None, 1, ps), scale_map),
-            pl.BlockSpec((None, 1, ps), scale_map),
+            pl.BlockSpec((None, None, 1, ps), scale_map),
+            pl.BlockSpec((None, None, 1, ps), scale_map),
             pl.BlockSpec((1, LUT_K),
                          lambda b_, h, j, tbl, lens: (0, 0)),
         ],
@@ -190,7 +196,7 @@ def paged_attention_4d(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, dv), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),

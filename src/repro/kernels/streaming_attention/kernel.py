@@ -18,9 +18,9 @@ normalised output on the last kv step.  VMEM working set per step:
 With block_q = block_k = 512 and d = 128 that is ~1.8 MiB — far under the
 ~16 MiB v5e VMEM budget and all matmul dims are multiples of 128 (MXU
 aligned).  The exponential inside the softmax is the UCLM LUT decomposition
-(``lut_exp_block`` — one-hot × table matmuls on the MXU), so this kernel is
-the full HASTILY story in one place: attention whose softmax *and* whose
-memory footprint are both restructured.
+(``lut_exp_block``, an exact select over the 128-entry table), so this
+kernel is the full HASTILY story in one place: attention whose softmax
+*and* whose memory footprint are both restructured.
 
 GQA: q heads are enumerated as B·Hq programs; the k/v index maps divide by
 the group size so each kv head's tiles are shared by its G query heads.

@@ -13,9 +13,14 @@ reductions cross the DCN, matching the v5e network hierarchy.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from repro.parallel.compat import make_mesh
+
+def make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axes (jax's default is Explicit): the
+    sharding rules here place arrays and leave propagation to the compiler."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -16,12 +16,12 @@ import argparse
 import asyncio
 import time
 
-import jax
 import numpy as np
 
 from repro.checkpoint import restore
 from repro.configs import get_config
-from repro.models import build_model
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models import init_params
 from repro.serving import (AsyncLMServer, EngineCore, Request,
                            SamplingParams, ServingEngine,
                            UnsupportedCacheLayout, start_metrics_server,
@@ -173,9 +173,9 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = init_params(cfg, seed=0)
     if args.ckpt_dir:
         ref = {"params": params}
         tree, step, _ = restore(args.ckpt_dir, ref)

@@ -18,6 +18,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ModelConfig
 from repro.core.attention_api import backend_for_config, get_backend
@@ -106,7 +107,7 @@ def build_model(cfg: ModelConfig) -> Model:
     if cfg.family == "encdec":
         return Model(
             cfg=cfg,
-            init=functools.partial(ED.encdec_init, cfg=cfg),
+            init=jax.jit(functools.partial(ED.encdec_init, cfg=cfg)),
             loss=functools.partial(ED.encdec_loss, cfg),
             init_cache=functools.partial(ED.encdec_cache_init, cfg),
             prefill=functools.partial(_encdec_prefill, cfg),
@@ -115,7 +116,7 @@ def build_model(cfg: ModelConfig) -> Model:
     if cfg.family == "bert":
         return Model(
             cfg=cfg,
-            init=functools.partial(LM.lm_init, cfg=cfg),
+            init=jax.jit(functools.partial(LM.lm_init, cfg=cfg)),
             loss=functools.partial(_bert_loss, cfg),
             init_cache=functools.partial(LM.trunk_cache_init, cfg),
             prefill=functools.partial(_bert_encode, cfg),
@@ -123,7 +124,7 @@ def build_model(cfg: ModelConfig) -> Model:
         )
     return Model(
         cfg=cfg,
-        init=functools.partial(LM.lm_init, cfg=cfg),
+        init=jax.jit(functools.partial(LM.lm_init, cfg=cfg)),
         loss=functools.partial(_lm_loss_with_labels, cfg),
         init_cache=functools.partial(LM.trunk_cache_init, cfg),
         prefill=functools.partial(_lm_prefill, cfg),
@@ -135,11 +136,21 @@ def build_model(cfg: ModelConfig) -> Model:
     )
 
 
-def init_params(cfg: ModelConfig, seed: int = 0) -> Params:
-    m = build_model(cfg)
-    if cfg.family == "encdec":
-        return ED.encdec_init(jax.random.PRNGKey(seed), cfg)
-    return LM.lm_init(jax.random.PRNGKey(seed), cfg)
+def init_params(cfg: ModelConfig, seed: int = 0, mesh: Any = None
+                ) -> Params:
+    """Seeded random params, built on the device by one jitted program.
+
+    ``Model.init`` is jitted, so each leaf's f32 draw, scale and cast to
+    the param dtype fuse: the resident peak is the finished params, not an
+    f32 copy of the largest stacked leaf beside them.  With ``mesh`` the
+    params are created replicated across it in place, not put on one
+    device and copied out.
+    """
+    init = build_model(cfg).init
+    if mesh is not None:
+        init = jax.jit(init,
+                       out_shardings=NamedSharding(mesh, PartitionSpec()))
+    return init(jax.random.PRNGKey(seed))
 
 
 # --------------------------------------------------------------------------

@@ -254,8 +254,8 @@ def attn_apply(cfg: ModelConfig, p: Params, x: jax.Array, *,
         #   all-scratch table row.
         assert xkv is None, "paged attention has no cross-attention path"
         # Tensor-parallel ragged step: the local pool shard's head count
-        # tells us the shard factor (static — compat.axis_size is traced on
-        # 0.4.x); the device index only feeds a dynamic_slice start.
+        # tells us the shard factor statically; the device index only feeds
+        # a dynamic_slice start.
         shards = 1
         if tp_axis is not None:
             assert token_pages is not None, \

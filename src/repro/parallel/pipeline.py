@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import shard_map
 
 Params = Any
 
@@ -43,9 +42,9 @@ def pipeline_apply(stage_fn: Callable[[Params, jax.Array], jax.Array],
     p_spec = jax.tree.map(lambda _: P(axis), stage_params)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(p_spec, P()), out_specs=P(),
-        check=False)
+        check_vma=False)
     def run(params, xs):
         params = jax.tree.map(lambda a: a[0], params)   # this stage's slice
         stage = jax.lax.axis_index(axis)

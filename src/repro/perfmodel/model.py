@@ -236,8 +236,13 @@ PLATFORMS: Dict[str, PlatformSpec] = {
 }
 
 
-def platform_spec(name: str | None = None) -> PlatformSpec:
-    return PLATFORMS.get(name or "", PLATFORMS["cpu"])
+def platform_spec(name: str) -> PlatformSpec:
+    """The row for ``name``; an unknown platform is an error, not a default
+    (a TPU scored with CPU constants would tune for the wrong machine)."""
+    if name not in PLATFORMS:
+        raise KeyError(f"no perfmodel platform {name!r}; known: "
+                       f"{sorted(PLATFORMS)}")
+    return PLATFORMS[name]
 
 
 def varlen_attention_traffic(segments, *, block_q: int, block_pages: int,

@@ -78,7 +78,8 @@ from repro.serving.api import (Request, RequestState, StepOutput,
                                UnsupportedCacheLayout)
 from repro.serving.paged import PagedKVCache
 from repro.serving.prefix_cache import RadixPrefixCache
-from repro.serving.sampling import (InvalidRequest, sample_row, stop_hit,
+from repro.serving.sampling import (NONFINITE_PICK, InvalidRequest,
+                                    sample_row, stop_hit,
                                     validate_stop_tokens)
 from repro.serving.scheduler import Scheduler
 from repro.serving.spec import NGramProposer
@@ -290,15 +291,14 @@ class EngineCore:
             # sampled tokens are a deterministic function of replicated
             # inputs (the all-gather rebuilt the head axis before wo), so
             # every device computes identical picks — out_specs P() is
-            # sound without a check pass (check=False: 0.4.x's rep checker
+            # sound without a check pass (the varying-manual-axes checker
             # cannot see through the kernel's custom calls).
             from jax.sharding import PartitionSpec
-            from repro.parallel import compat
             rep = PartitionSpec()
-            ragged_fn = compat.shard_map(
+            ragged_fn = jax.shard_map(
                 ragged_fn, mesh=self.mesh,
                 in_specs=(rep, self._pool_specs) + (rep,) * 10,
-                out_specs=(rep, self._pool_specs), check=False)
+                out_specs=(rep, self._pool_specs), check_vma=False)
 
         # donated pool: every layer's row writes update in place instead of
         # copying the whole pool each step.
@@ -324,8 +324,8 @@ class EngineCore:
                     f"mesh of {mesh} devices requested but only "
                     f"{len(jax.devices())} visible (set XLA_FLAGS="
                     f"--xla_force_host_platform_device_count for CPU tests)")
-            from repro.parallel import compat
-            return compat.make_mesh((mesh,), ("model",))
+            from repro.launch.mesh import make_mesh
+            return make_mesh((mesh,), ("model",))
         if "model" not in mesh.axis_names:
             raise ValueError(f"serving mesh needs a 'model' axis, got "
                              f"{mesh.axis_names}")
@@ -470,7 +470,13 @@ class EngineCore:
             jnp.asarray(batch.tokens), jnp.asarray(batch.pos),
             jnp.asarray(last_idx), jnp.asarray(cu),
             *self._sampling_inputs(plans))
-        return self._finish(plans, preempted, picks=np.asarray(picks),
+        picks = np.asarray(picks)
+        bad = [p.run.req.uid for p, row in zip(plans, picks)
+               if np.any(row == NONFINITE_PICK)]
+        if bad:
+            raise FloatingPointError(
+                f"non-finite logits in this step for requests {bad}")
+        return self._finish(plans, preempted, picks=picks,
                             live=batch.live, padded=batch.width)
 
     def _sampling_inputs(self, plans):
@@ -653,25 +659,12 @@ class EngineCore:
                      * jnp.dtype(jnp.float32).itemsize)
         return self.cfg.num_layers * per_layer * (n - 1) // n
 
-    def measure_collective_bytes(self, width: Optional[int] = None) -> int:
-        """*Measured* per-device collective wire bytes for one compiled
-        ragged step, by walking the step's optimized HLO with
-        :func:`repro.launch.hlo_analysis.hlo_totals` — the cross-check for
-        the analytic :attr:`collective_bytes_per_token` (measured ≈
-        analytic × stream width: every packed row, live or dead, runs the
-        per-layer head all-gather).
-
-        AOT: lowers and compiles the step at ``width`` (default: the
-        widest token bucket) and the current table-width high-water mark
-        without executing anything — but compiling *is* tracing, so call
-        this before ``obs.mark_warm()`` or the sentinel counts it as a
-        retrace.  Publishes the ``collective_bytes_per_step`` gauge;
-        returns 0 off-mesh.
-        """
-        if self.mesh is None or self._ragged is None:
-            self.obs.g_coll_per_step.set(0)
-            return 0
-        from repro.launch.hlo_analysis import hlo_totals
+    def compiled_step_hlo(self, width: Optional[int] = None) -> str:
+        """Optimized HLO text of the ragged step, compiled ahead of time at
+        ``width`` (default: the widest token bucket) and the current
+        table-width high-water mark; nothing executes.  Compiling *is*
+        tracing, so call this before ``obs.mark_warm()`` or the sentinel
+        counts it as a retrace."""
         t = int(width or self.scheduler.token_buckets[-1])
         pw = self.scheduler._table_pages
         lanes = self.lanes
@@ -688,6 +681,22 @@ class EngineCore:
                 jnp.ones((lanes,), jnp.float32),
                 jnp.zeros((lanes,), jnp.uint32),
                 jnp.zeros((lanes,), jnp.int32))
+        return self._ragged.lower(*args).compile().as_text()
+
+    def measure_collective_bytes(self, width: Optional[int] = None) -> int:
+        """*Measured* per-device collective wire bytes for one compiled
+        ragged step, by walking the step's optimized HLO
+        (:meth:`compiled_step_hlo`) with
+        :func:`repro.launch.hlo_analysis.hlo_totals` — the cross-check for
+        the analytic :attr:`collective_bytes_per_token` (measured ≈
+        analytic × stream width: every packed row, live or dead, runs the
+        per-layer head all-gather).  Publishes the
+        ``collective_bytes_per_step`` gauge; returns 0 off-mesh.
+        """
+        if self.mesh is None or self._ragged is None:
+            self.obs.g_coll_per_step.set(0)
+            return 0
+        from repro.launch.hlo_analysis import hlo_totals
         try:
             # The trunk is a lax.scan over layer periods — one while loop
             # at depth 0 whose body must be multiplied by the trip count.
@@ -696,7 +705,7 @@ class EngineCore:
             hints = [int(nper)]
         except Exception:
             hints = None
-        hlo = self._ragged.lower(*args).compile().as_text()
+        hlo = self.compiled_step_hlo(width)
         total = int(hlo_totals(hlo, trip_hints=hints)["total_wire_bytes"])
         self.obs.g_coll_per_step.set(total)
         return total

@@ -41,6 +41,10 @@ import numpy as np
 from repro.core.lut_exp import lut_exp
 from repro.core.lut_softmax import NEG_INF, lut_log_softmax, lut_softmax
 
+# In-step pick for a logit row whose max is NaN or ±inf: never a vocab id,
+# so the engine can refuse the step instead of streaming a garbage token.
+NONFINITE_PICK = -1
+
 
 class InvalidRequest(ValueError):
     """A request that can never be served correctly, rejected at
@@ -251,10 +255,14 @@ def sample_in_step(logits: jax.Array, *, temperature: jax.Array,
     form ``(lanes, 1+k, V)`` → ``(lanes, 1+k)``: row 0 samples with the
     lane's params, rows ≥ 1 are forced greedy — they are the verify rows,
     and the acceptance rule is argmax equality (the proposer only drafts
-    for greedy lanes, so row 0 of a drafting lane is greedy too)."""
+    for greedy lanes, so row 0 of a drafting lane is greedy too).
+
+    A row whose max logit is not finite picks :data:`NONFINITE_PICK`."""
+    finite = jnp.isfinite(jnp.max(logits, axis=-1))
     if logits.ndim == 2:
-        return sample_rows(logits, temperature, top_k, top_p, seed, counter,
+        toks = sample_rows(logits, temperature, top_k, top_p, seed, counter,
                            exp_fn=exp_fn)
+        return jnp.where(finite, toks, NONFINITE_PICK)
     lanes, r, v = logits.shape
     col0 = jnp.arange(r, dtype=jnp.int32)[None, :] == 0
     t = jnp.where(col0, jnp.asarray(temperature, jnp.float32)[:, None],
@@ -262,7 +270,7 @@ def sample_in_step(logits: jax.Array, *, temperature: jax.Array,
     rep = lambda a: jnp.repeat(jnp.asarray(a), r, axis=0)   # noqa: E731
     toks = sample_rows(logits.reshape(lanes * r, v), t, rep(top_k),
                        rep(top_p), rep(seed), rep(counter), exp_fn=exp_fn)
-    return toks.reshape(lanes, r)
+    return jnp.where(finite, toks.reshape(lanes, r), NONFINITE_PICK)
 
 
 _jit_sample_rows = jax.jit(sample_rows)

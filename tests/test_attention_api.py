@@ -129,7 +129,8 @@ def test_ring_backend_via_shard_map(rng):
     the 4/8-chip equivalence lives in test_ring_attention.py)."""
     import functools
     from jax.sharding import PartitionSpec as P
-    from repro.parallel.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.launch.mesh import make_mesh
     q, k, v = qkv(rng)
     mesh = make_mesh((1,), ("sp",))
     f = shard_map(

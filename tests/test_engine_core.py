@@ -384,7 +384,7 @@ def test_ragged_graph_has_no_padded_intermediate():
     adjacent pair with any smoke-config dimension or the T=48 stream, so a
     hit can only be the padded block.  The padded step itself is the
     sanity check that the detector fires."""
-    from tests.test_paged_serving import _jaxpr_shapes
+    from tests._jaxpr import jaxpr_shapes
 
     cfg, params = build()
     lanes, chunk, ps = 3, 24, 8
@@ -403,7 +403,7 @@ def test_ragged_graph_has_no_padded_intermediate():
                 if any(s[i] == lanes and s[i + 1] == chunk
                        for i in range(len(s) - 1))]
 
-    bad = padded_pairs(_jaxpr_shapes(jaxpr.jaxpr))
+    bad = padded_pairs(jaxpr_shapes(jaxpr.jaxpr))
     assert not bad, f"(lanes, C)-padded intermediate in ragged graph: {bad}"
 
     # sanity: the detector does catch the padded step's block
@@ -412,7 +412,7 @@ def test_ragged_graph_has_no_padded_intermediate():
         jnp.full((lanes, pw), eng.kv.scratch, jnp.int32),
         jnp.zeros((lanes, chunk), jnp.int32),
         jnp.zeros((lanes,), jnp.int32), jnp.zeros((lanes,), jnp.int32))
-    assert padded_pairs(_jaxpr_shapes(padded.jaxpr))
+    assert padded_pairs(jaxpr_shapes(padded.jaxpr))
 
 
 # ------------------------------------------------ scheduler pack properties --

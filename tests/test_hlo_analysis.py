@@ -56,7 +56,8 @@ def test_real_lowered_psum_counted():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, functools
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.launch.hlo_analysis import collective_totals
 
         mesh = make_mesh((4,), ("m",))

@@ -87,6 +87,12 @@ def test_traffic_grid_steps_fall_with_block_pages():
     assert all(a > b for a, b in zip(steps, steps[1:])), steps
 
 
+def test_unknown_platform_is_an_error():
+    """No silent fallback to the cpu row for a platform the model lacks."""
+    with pytest.raises(KeyError, match="gpu"):
+        platform_spec("gpu")
+
+
 def test_roofline_terms():
     """max(mem, compute) + dispatch, plus the per-page dequant penalty only
     when dequant='page' actually splits the multiply."""

@@ -81,3 +81,16 @@ def test_lut_exp2():
 def test_grad_flows_through():
     g = jax.grad(lambda x: lut_exp(x))(1.0)
     assert np.isfinite(g) and abs(g - np.e) / np.e < 0.01
+
+
+@pytest.mark.parametrize("shape", [(8, 128), (32, 16), (3, 5)])
+def test_kernel_table_lookup_is_exact(shape):
+    """The kernels' select lookup returns T[d] bit for bit, as the gather
+    in ``core.lut_exp`` does, for every index."""
+    from repro.kernels.lut_exp.kernel import table_lookup
+    size = int(np.prod(shape))
+    d = (np.arange(size, dtype=np.int32) * 37 % K).reshape(shape)
+    table = make_table()
+    got = np.asarray(table_lookup(jnp.asarray(d), table))
+    np.testing.assert_array_equal(got, np.take(np.asarray(table), d))
+

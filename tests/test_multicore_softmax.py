@@ -6,7 +6,8 @@ def test_sharded_softmax_matches_full():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np, functools
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.parallel.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.multicore_softmax import (sharded_softmax,
                                                   sharded_softmax_tree)
         from repro.core.lut_softmax import lut_softmax
@@ -38,7 +39,8 @@ def test_tree_allreduce_is_logn():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, functools
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.multicore_softmax import tree_allreduce
 
         mesh = make_mesh((8,), ("m",))

@@ -14,6 +14,7 @@ from repro.configs import get_config
 from repro.models import build_model
 from repro.serving import (EngineCore, PagedServingEngine, Request,
                            ServingEngine, UnsupportedCacheLayout)
+from tests._jaxpr import jaxpr_shapes
 
 warnings.filterwarnings("ignore", category=DeprecationWarning,
                         module="repro.serving.engine")
@@ -160,27 +161,6 @@ def test_lazy_page_growth():
 
 # ------------------------------------------------------- in-place serving --
 
-def _jaxpr_shapes(jaxpr):
-    """Every intermediate array shape in a jaxpr, nested subjaxprs included
-    (pjit bodies, scan bodies, vmap — wherever the gather could hide)."""
-    def sub(val):
-        vals = val if isinstance(val, (list, tuple)) else [val]
-        for v in vals:
-            if isinstance(v, jax.core.ClosedJaxpr):
-                yield v.jaxpr
-            elif isinstance(v, jax.core.Jaxpr):
-                yield v
-
-    for eqn in jaxpr.eqns:
-        for v in eqn.outvars:
-            aval = getattr(v, "aval", None)
-            if aval is not None and hasattr(aval, "shape"):
-                yield tuple(aval.shape)
-        for val in eqn.params.values():
-            for j in sub(val):
-                yield from _jaxpr_shapes(j)
-
-
 def _step_jaxpr(eng, *, width, c, kv_len, q_len, npages):
     """Trace the engine's unified step at a given (chunk, table-width)."""
     tbl = np.full((eng.lanes, width), eng.kv.scratch, np.int32)
@@ -207,14 +187,14 @@ def test_decode_graph_has_no_gathered_view(kv_quant):
 
     jaxpr = _step_jaxpr(eng, width=width, c=1, kv_len=[151, 0],
                         q_len=[1, 0], npages=13)
-    bad = [s for s in _jaxpr_shapes(jaxpr.jaxpr) if gathered_len in s]
+    bad = [s for s in jaxpr_shapes(jaxpr.jaxpr) if gathered_len in s]
     assert not bad, f"gathered cache view in decode graph: {bad}"
 
     # sanity: the detector does catch the legacy gather copy
     tbl = np.full((2, width), eng.kv.scratch, np.int32)
     legacy = jax.make_jaxpr(
         lambda pool: eng.kv.gather(pool, jnp.asarray(tbl)))(eng.kv.pool)
-    assert any(gathered_len in s for s in _jaxpr_shapes(legacy.jaxpr))
+    assert any(gathered_len in s for s in jaxpr_shapes(legacy.jaxpr))
 
 
 @pytest.mark.parametrize("kv_quant", [False, True])
@@ -232,7 +212,7 @@ def test_chunked_prefill_graph_has_no_contiguous_cache(kv_quant):
     jaxpr = _step_jaxpr(eng, width=width, c=chunk, kv_len=[120, 0],
                         q_len=[chunk, 0], npages=10)
     contiguous = {width * ps, 13 * ps, 150}
-    bad = [s for s in _jaxpr_shapes(jaxpr.jaxpr)
+    bad = [s for s in jaxpr_shapes(jaxpr.jaxpr)
            if contiguous.intersection(s)]
     assert not bad, f"contiguous KV intermediate in chunk graph: {bad}"
     # and write_prefill itself is gone from the pool API

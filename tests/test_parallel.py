@@ -8,7 +8,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import ASSIGNED, get_config
 from repro.models import build_model
 from repro.parallel import fit_spec, param_pspec, param_specs
-from repro.parallel.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from tests._multidevice import run_with_devices
 
 
@@ -30,7 +30,7 @@ def test_param_specs_always_divisible():
         from repro.models import build_model, input_specs
         from repro.parallel import param_specs, batch_specs, cache_specs
         from repro.launch.mesh import make_production_mesh
-        from repro.parallel.compat import make_mesh
+        from repro.launch.mesh import make_mesh
 
         # 16-device stand-in mesh with the production axis names
         mesh = make_mesh((4, 4), ("data", "model"))
@@ -77,7 +77,7 @@ def test_pipeline_parallel_matches_sequential():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.parallel import pipeline_apply
-        from repro.parallel.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((4,), ("pod",))
         rng = np.random.default_rng(0)
         S, M, mb, d = 4, 6, 3, 8
@@ -108,7 +108,7 @@ def test_sharded_train_step_matches_single():
         from repro.models import build_model
         from repro.parallel import (param_specs, batch_specs, shard_tree,
                                     activation_sharding)
-        from repro.parallel.compat import make_mesh
+        from repro.launch.mesh import make_mesh
 
         cfg = get_config("deepseek-7b-smoke")
         model = build_model(cfg)

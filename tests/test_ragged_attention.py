@@ -23,6 +23,7 @@ from repro.kernels.paged_attention import (paged_attention,
                                            q_block_layout,
                                            validate_cu_seqlens,
                                            varlen_positions)
+from tests._jaxpr import iter_eqns
 
 
 def make_pool(rng, n, hkv, ps, d):
@@ -392,19 +393,10 @@ def test_validate_cu_seqlens_raises():
 def _pool_gather_rows(jaxpr, pool_shape):
     """Total rows gathered from pool-shaped operands anywhere in the graph
     (scan bodies included) — the structural KV-traffic count."""
-    total = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "gather" and \
-                tuple(eqn.invars[0].aval.shape) == pool_shape:
-            total += int(np.prod(eqn.invars[1].aval.shape[:-1]))
-        for val in eqn.params.values():
-            vals = val if isinstance(val, (list, tuple)) else [val]
-            for v in vals:
-                if isinstance(v, jax.core.ClosedJaxpr):
-                    total += _pool_gather_rows(v.jaxpr, pool_shape)
-                elif isinstance(v, jax.core.Jaxpr):
-                    total += _pool_gather_rows(v, pool_shape)
-    return total
+    return sum(int(np.prod(eqn.invars[1].aval.shape[:-1]))
+               for eqn in iter_eqns(jaxpr)
+               if eqn.primitive.name == "gather"
+               and tuple(eqn.invars[0].aval.shape) == pool_shape)
 
 
 def test_tiled_page_gathers_scale_with_block_count(rng):

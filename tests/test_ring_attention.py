@@ -6,7 +6,8 @@ def test_ring_attention_matches_naive():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np, functools
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.ring_attention import ring_attention
         from repro.core.streaming_attention import naive_attention
 
@@ -37,7 +38,8 @@ def test_distributed_decode_matches_naive():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np, functools
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.ring_attention import distributed_decode_attention
         from repro.core.streaming_attention import naive_attention
 

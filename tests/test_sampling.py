@@ -121,6 +121,42 @@ def test_in_step_greedy_matches_host_tie_break():
     assert (np.asarray(greedy_rows(lg)) == picks).all()
 
 
+@pytest.mark.parametrize("bad", [np.nan, np.inf])
+def test_nonfinite_logit_row_picks_sentinel(bad):
+    """A row whose max logit is NaN or +inf picks NONFINITE_PICK (never a
+    vocab id); finite rows, -inf masks included, sample as before."""
+    from repro.serving.sampling import NONFINITE_PICK, sample_in_step
+    rng = np.random.default_rng(0)
+    lg = rng.normal(size=(3, 33)).astype(np.float32)
+    lg[1, 7] = bad
+    lg[2, :5] = -np.inf
+    z = np.zeros((3,), np.int32)
+    kw = dict(temperature=np.zeros((3,), np.float32), top_k=z,
+              top_p=np.ones((3,), np.float32), seed=z.astype(np.uint32),
+              counter=z)
+    picks = np.asarray(sample_in_step(jnp.asarray(lg), **kw))
+    want = np.asarray(greedy_rows(lg))
+    assert picks[1] == NONFINITE_PICK
+    assert picks[0] == want[0] and picks[2] == want[2]
+    spec = np.asarray(sample_in_step(jnp.asarray(np.stack([lg, lg], 1)),
+                                     **kw))
+    assert (spec[1] == NONFINITE_PICK).all() and (spec[0] == want[0]).all()
+
+
+def test_engine_refuses_a_step_with_nonfinite_logits():
+    """NaN weights reach the logits: the step raises instead of streaming
+    a garbage token."""
+    cfg, params = build()
+    params["final_norm"] = jax.tree.map(lambda a: a * jnp.nan,
+                                        params["final_norm"])
+    eng = EngineCore(cfg, params, lanes=2, page_size=8, num_pages=16,
+                     chunk_size=8)
+    eng.submit(Request(uid=3, prompt=prompts_for(cfg, 0, [5])[0],
+                       max_new=2))
+    with pytest.raises(FloatingPointError, match=r"\[3\]"):
+        eng.run()
+
+
 @pytest.mark.parametrize("kv_quant", [False, True])
 @pytest.mark.parametrize("feature", ["plain", "spec", "prefix"])
 def test_temperature_zero_identity_across_matrix(kv_quant, feature):
@@ -405,7 +441,7 @@ def test_sampling_runs_inside_ragged_step_jaxpr():
     round-trip between logits and token; (2) the sampling region (the
     sort-based top-k/top-p masks) operates on the (lanes, V) last-idx
     gather only — never on a (T, V) full-stream tensor."""
-    from tests.test_paged_serving import _jaxpr_shapes
+    from tests._jaxpr import iter_eqns, jaxpr_shapes
 
     cfg, params = build()
     lanes, t, pw = 3, 48, 4
@@ -425,22 +461,12 @@ def test_sampling_runs_inside_ragged_step_jaxpr():
 
     # sampling region shape: every sort in the graph runs on the
     # (lanes, V) gathered rows — none on the (T, V) packed stream
-    def sorts(jx, acc):
-        for eqn in jx.eqns:
-            if eqn.primitive.name == "sort":
-                acc.append(tuple(eqn.invars[0].aval.shape))
-            for val in eqn.params.values():
-                for sub in (val if isinstance(val, (list, tuple)) else [val]):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
-                        sorts(sub.jaxpr, acc)
-                    elif isinstance(sub, jax.core.Jaxpr):
-                        sorts(sub, acc)
-        return acc
-
-    seen = sorts(jaxpr.jaxpr, [])
+    seen = [tuple(eqn.invars[0].aval.shape)
+            for eqn in iter_eqns(jaxpr.jaxpr)
+            if eqn.primitive.name == "sort"]
     assert seen, "sampling region not found in the traced step"
     assert set(seen) == {(lanes, v)}, seen
     assert all(s[0] != t for s in seen)
     # and no (T, V) tensor exists anywhere (logits stay last-idx-gathered)
-    assert all(s[-2:] != (t, v) for s in _jaxpr_shapes(jaxpr.jaxpr)
+    assert all(s[-2:] != (t, v) for s in jaxpr_shapes(jaxpr.jaxpr)
                if len(s) >= 2)

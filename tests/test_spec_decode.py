@@ -29,6 +29,8 @@ tables and cursors identical to never having drafted.  Covered here:
   (lanes, k) KV), and k is a static shape — draft counts varying 0..k
   retrace nothing.
 """
+from collections import Counter
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -542,21 +544,6 @@ def test_page_pressure_degrades_drafts_not_residents():
 
 # -------------------------------------------------- compile-level gates --
 
-def _prim_counts(jaxpr, acc=None):
-    """Histogram of primitive names, nested subjaxprs included."""
-    acc = {} if acc is None else acc
-    for eqn in jaxpr.eqns:
-        acc[eqn.primitive.name] = acc.get(eqn.primitive.name, 0) + 1
-        for val in eqn.params.values():
-            vals = val if isinstance(val, (list, tuple)) else [val]
-            for v in vals:
-                if isinstance(v, jax.core.ClosedJaxpr):
-                    _prim_counts(v.jaxpr, acc)
-                elif isinstance(v, jax.core.Jaxpr):
-                    _prim_counts(v, acc)
-    return acc
-
-
 def test_verify_graph_is_one_varlen_attend():
     """The verify step is the SAME graph as the plain ragged step — the
     drafted rows ride the packed stream through one varlen attend.  The
@@ -566,7 +553,7 @@ def test_verify_graph_is_one_varlen_attend():
     (lanes, C)-padded intermediate, and no rank ≥ 4 (lanes, 1+k)-leading
     gathered-KV tensor."""
     from tests.test_engine_core import _sampling_args
-    from tests.test_paged_serving import _jaxpr_shapes
+    from tests._jaxpr import iter_eqns, jaxpr_shapes
 
     cfg, params = build()
     lanes, k, ps = 3, 4, 8
@@ -585,7 +572,7 @@ def test_verify_graph_is_one_varlen_attend():
     plain_jaxpr = jax.make_jaxpr(eng._ragged)(
         *args, jnp.zeros((lanes,), jnp.int32), cu, *_sampling_args(lanes))
 
-    spec_c, plain_c = (_prim_counts(j.jaxpr)
+    spec_c, plain_c = (Counter(e.primitive.name for e in iter_eqns(j.jaxpr))
                        for j in (spec_jaxpr, plain_jaxpr))
     for prim in ("dot_general", "scan", "while"):
         assert spec_c.get(prim, 0) == plain_c.get(prim, 0), (
@@ -593,7 +580,7 @@ def test_verify_graph_is_one_varlen_attend():
             f"the verify step added compute beyond the logit gather")
     assert spec_c.get("dot_general", 0) > 0      # sanity: detector sees ops
 
-    shapes = list(_jaxpr_shapes(spec_jaxpr.jaxpr))
+    shapes = list(jaxpr_shapes(spec_jaxpr.jaxpr))
     bad = [s for s in shapes
            if len(s) >= 4 and s[0] == lanes and s[1] == k + 1]
     assert not bad, f"(lanes, 1+k)-gathered KV intermediate: {bad}"

@@ -1,0 +1,86 @@
+"""The main path's Pallas kernels compile for one TPU v5e chip.
+
+No chip is needed: the TPU compiler is installed and compiles for a chip
+that is described, not attached.  This catches what interpret mode
+accepts and Mosaic refuses (blocks that break the tiling rule, dynamic
+slices of values, lane-flattening reshapes).  Shapes are deepseek-7b's:
+32 KV heads of 128, pages of 16 rows, q-blocks of 32.
+"""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.lut_exp import make_table
+from repro.kernels.lut_exp.kernel import lut_exp_2d
+from repro.kernels.paged_attention import paged_attention_varlen
+from repro.kernels.streaming_attention.kernel import attention_3d
+
+ops = importlib.import_module("repro.kernels.paged_attention.ops")
+
+H, D, PS, BQ = 32, 128, 16, 32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:                                # noqa: BLE001
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("exp_mode", ["lut", "exact"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_varlen_paged_kernel_compiles(one_chip, monkeypatch, kv, exp_mode):
+    # The entry point picks the kernel by the default backend, which is
+    # the CPU here; the compile targets the described chip.
+    monkeypatch.setattr(ops, "_use_kernel", lambda: True)
+    t, n, p, lanes = 64, 64, 8, 4
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((n, H, PS, D), jnp.int8 if kv == "int8" else jnp.bfloat16)
+    scale = sds((n, H, PS), jnp.float32) if kv == "int8" else None
+
+    def step(q, kp, vp, ks, vs, pages, pos, cu):
+        return paged_attention_varlen(
+            q, kp, vp, pages, pos, cu_seqlens=cu, exp_mode=exp_mode,
+            k_scale=ks, v_scale=vs, block_q=BQ, interpret=False)
+
+    hlo = _compiled_text(step, sds((t, H, D), jnp.bfloat16), pool, pool,
+                         scale, scale, sds((t, p), jnp.int32),
+                         sds((t,), jnp.int32), sds((lanes + 1,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_streaming_kernel_compiles_at_block_512(one_chip):
+    x = jax.ShapeDtypeStruct((H, 512, D), jnp.bfloat16, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one_chip)
+
+    def attend(q, k, v, tab):
+        return attention_3d(q, k, v, tab, scale=D ** -0.5, causal=True,
+                            window=None, cap=None, exp_mode="lut",
+                            block_q=512, block_k=512, kv_len=512,
+                            q_offset=0, group=1)
+
+    assert "tpu_custom_call" in _compiled_text(attend, x, x, x, table)
+
+
+def test_lut_exp_kernel_compiles(one_chip):
+    x = jax.ShapeDtypeStruct((512, 128), jnp.float32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct(make_table().shape, jnp.float32,
+                                 sharding=one_chip)
+    assert "tpu_custom_call" in _compiled_text(
+        lambda a, tab: lut_exp_2d(a, tab, block_m=256), x, table)
