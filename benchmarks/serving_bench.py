@@ -577,12 +577,20 @@ class _JunkProposer:
 
 def _spec_drain(eng, requests) -> Dict[str, Any]:
     """Drain one pass and attach the pass's speculative deltas — a
-    registry window (``spec_window``/``spec_summary``), not bench-side
-    diffing of engine attributes."""
-    since = eng.obs.spec_window()
+    registry window (``snapshot``/``delta``), not bench-side diffing of
+    engine attributes."""
+    since = eng.obs.registry.snapshot()
     res = _instrumented_drain(
         eng, requests, lambda e: e.pages_in_use * e.kv.page_size, core=True)
-    res.update(eng.obs.spec_summary(since))
+    d = eng.obs.registry.delta(since)
+    drafted = d.get("spec_drafted_tokens_total", 0)
+    accepted = d.get("spec_accepted_tokens_total", 0)
+    spec_steps = d.get("spec_steps_total", 0)
+    res.update(drafted_tokens=int(drafted), accepted_tokens=int(accepted),
+               spec_steps=int(spec_steps),
+               acceptance=accepted / drafted if drafted else 0.0,
+               accepted_per_spec_step=(accepted / spec_steps
+                                       if spec_steps else 0.0))
     return res
 
 
@@ -822,7 +830,8 @@ def _serve_loop_results(tiny: bool) -> Dict[str, Any]:
         ``request_ttft_ms`` / ``request_tpot_ms`` histograms (windowed by
         observation count), not re-derived from per-step polling."""
         reqs = _serve_traffic(cfg.vocab_size, n, max_new, seed)
-        window = eng.obs.engine_window()
+        ttft, tpot = eng.obs.h_ttft_ms, eng.obs.h_tpot_ms
+        ttft_n, tpot_n = ttft.count(), tpot.count()
         t0 = time.perf_counter()
         for r in reqs:
             eng.submit(r)
@@ -832,8 +841,10 @@ def _serve_loop_results(tiny: bool) -> Dict[str, Any]:
             steps += 1
         elapsed = time.perf_counter() - t0
         eng.finished.clear()
-        res = {"req_s": n / elapsed, "steps": steps}
-        res.update(eng.obs.engine_latency_summary(window))
+        res = {"req_s": n / elapsed, "steps": steps,
+               "ttft_ms_p50": ttft.percentile(0.50, skip=ttft_n),
+               "ttft_ms_p99": ttft.percentile(0.99, skip=ttft_n),
+               "tpot_ms": tpot.mean(skip=tpot_n)}
         return res, elapsed
 
     drain(_serve_traffic(cfg.vocab_size, n, max_new, seed=0))   # warm jits

@@ -52,6 +52,10 @@ from repro.core.lut_softmax import NEG_INF
 from repro.kernels.lut_exp.kernel import lut_exp_block
 
 LANES = 128  # m/l carries are broadcast across one lane register
+# The kernel's name in compiled programs and profiler traces.  Its device
+# ops are found by the substring "paged_attention", which no other op of
+# the serving step holds.
+KERNEL_NAME = "paged_attention_varlen"
 
 
 def _exp_fn(mode: str, table):
@@ -195,6 +199,7 @@ def paged_attention_4d(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
+        name=KERNEL_NAME,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),

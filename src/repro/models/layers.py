@@ -26,6 +26,18 @@ from repro.core.streaming_attention import (quantize_kv_rows,
 
 Params = Dict[str, Any]
 
+# Device regions of the serving step, as ``jax.named_scope`` names.  XLA
+# keeps them in each op's metadata (``op_name``), so a profiler trace can
+# name the region every device op belongs to: the embedding, per layer its
+# attention (with the KV pool update inside it) and MLP, the head (final
+# norm, last-row gather, unembedding) and the in-step sampler.
+SCOPE_EMBED = "embed"
+SCOPE_ATTENTION = "attention"
+SCOPE_KV_WRITE = "kv_write"
+SCOPE_MLP = "mlp"
+SCOPE_HEAD = "head"
+SCOPE_SAMPLE = "sample"
+
 
 def _dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
@@ -300,8 +312,9 @@ def attn_apply(cfg: ModelConfig, p: Params, x: jax.Array, *,
             # (B, L) page/offset indices scatter one row at a time — the
             # transient is O(B·L), never the (B, P·ps, …) gathered view.
             # (Ragged: B == 1, L == T, indices shaped (1, T).)
-            return pool.at[pids, :, off].set(
-                jnp.moveaxis(val, 2, 1).astype(pool.dtype))
+            with jax.named_scope(SCOPE_KV_WRITE):
+                return pool.at[pids, :, off].set(
+                    jnp.moveaxis(val, 2, 1).astype(pool.dtype))
 
         attn_kw = dict(scale=scale_default, cap=cfg.attn_softcap,
                        window=window, exp_mode=cfg.exp_mode)

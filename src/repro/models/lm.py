@@ -85,25 +85,25 @@ def _layer_apply(cfg: ModelConfig, kind: str, p: Params, x: jax.Array, *,
         h, new_cache = apply(cfg, p["mix"], L.norm_apply(cfg, p["ln"], x),
                              cache=cache)
         return x + h, new_cache, aux
-    a, new_cache = L.attn_apply(cfg, p["attn"], L.norm_apply(cfg, p["ln1"], x),
-                                kind=kind, pos=pos, causal=causal,
-                                cache=cache, cache_index=cache_index,
-                                page_table=page_table, q_len=q_len,
-                                token_pages=token_pages,
-                                cu_seqlens=cu_seqlens,
-                                kernel_config=kernel_config,
-                                tp_axis=tp_axis)
-    if cfg.post_block_norm:
-        a = L.norm_apply(cfg, p["ln1_post"], a)
-    x = x + a
-    h_in = L.norm_apply(cfg, p["ln2"], x)
-    if cfg.family == "moe":
-        h, aux = moe_apply(cfg, p["moe"], h_in)
-    else:
-        h = L.mlp_apply(cfg, p["mlp"], h_in)
-    if cfg.post_block_norm:
-        h = L.norm_apply(cfg, p["ln2_post"], h)
-    return x + h, new_cache, aux
+    with jax.named_scope(L.SCOPE_ATTENTION):
+        a, new_cache = L.attn_apply(
+            cfg, p["attn"], L.norm_apply(cfg, p["ln1"], x), kind=kind,
+            pos=pos, causal=causal, cache=cache, cache_index=cache_index,
+            page_table=page_table, q_len=q_len, token_pages=token_pages,
+            cu_seqlens=cu_seqlens, kernel_config=kernel_config,
+            tp_axis=tp_axis)
+        if cfg.post_block_norm:
+            a = L.norm_apply(cfg, p["ln1_post"], a)
+        x = x + a
+    with jax.named_scope(L.SCOPE_MLP):
+        h_in = L.norm_apply(cfg, p["ln2"], x)
+        if cfg.family == "moe":
+            h, aux = moe_apply(cfg, p["moe"], h_in)
+        else:
+            h = L.mlp_apply(cfg, p["mlp"], h_in)
+        if cfg.post_block_norm:
+            h = L.norm_apply(cfg, p["ln2_post"], h)
+        return x + h, new_cache, aux
 
 
 def _layer_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int
@@ -442,23 +442,28 @@ def lm_step_ragged(cfg: ModelConfig, params: Params, tokens: jax.Array,
     embed/norms/MLP/unembed/sampling run replicated and unchanged.
     """
     p_tok = jnp.asarray(pos, jnp.int32)
-    x = L.embed_apply(cfg, params["embed"], tokens[None], p_tok[None])
+    with jax.named_scope(L.SCOPE_EMBED):
+        x = L.embed_apply(cfg, params["embed"], tokens[None], p_tok[None])
     x, caches, _ = trunk_apply(cfg, params["trunk"], x, pos=p_tok[None],
                                caches=caches, cache_index=None, causal=True,
                                token_pages=token_pages, cu_seqlens=cu_seqlens,
                                kernel_config=kernel_config, tp_axis=tp_axis)
-    x = L.norm_apply(cfg, params["final_norm"], x)
-    # (lanes,) gather BEFORE unembedding: the (T, V) logits tensor would be
-    # the largest activation of the step; only lanes' last rows are needed.
-    idx = jnp.asarray(last_idx, jnp.int32)
-    x = jnp.take(x[0], idx, axis=0)       # (lanes, D) or (lanes, 1+k, D)
-    logits = L.unembed_apply(cfg, params["embed"], params.get("lm_head"), x)
-    spec = ("dp", "tp") if idx.ndim == 1 else ("dp", None, "tp")
-    logits = maybe_shard(logits, spec)
+    with jax.named_scope(L.SCOPE_HEAD):
+        x = L.norm_apply(cfg, params["final_norm"], x)
+        # (lanes,) gather BEFORE unembedding: the (T, V) logits tensor
+        # would be the largest activation of the step; only lanes' last
+        # rows are needed.
+        idx = jnp.asarray(last_idx, jnp.int32)
+        x = jnp.take(x[0], idx, axis=0)   # (lanes, D) or (lanes, 1+k, D)
+        logits = L.unembed_apply(cfg, params["embed"], params.get("lm_head"),
+                                 x)
+        spec = ("dp", "tp") if idx.ndim == 1 else ("dp", None, "tp")
+        logits = maybe_shard(logits, spec)
     if sampling is None:
         return logits, caches
     # In-step sampling: logits → tokens without leaving the graph.
     # Deferred import — repro.serving imports repro.models at module load;
     # resolving the sampler at trace time keeps the packages acyclic.
     from repro.serving.sampling import sample_in_step
-    return sample_in_step(logits, **sampling), caches
+    with jax.named_scope(L.SCOPE_SAMPLE):
+        return sample_in_step(logits, **sampling), caches

@@ -65,7 +65,6 @@ form for code that still threads its own key.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, List, Optional
 
 import jax
@@ -367,12 +366,17 @@ class EngineCore:
         chunked prefill, decode, admission, preemption — happen here; the
         engine's ``mode`` picks the packing (ragged stream / padded block),
         the token streams are identical either way."""
-        t0 = time.perf_counter()
-        out = (self._step_ragged() if self.mode == "ragged"
-               else self._step_padded())
+        # The step number matches the step ring's record of this step; a
+        # metrics-off engine counts no steps, so its spans carry none.
+        obs = self.obs
+        step = ({"step": int(obs.c_steps.value()) + 1} if obs.enabled
+                else {})
+        with obs.span("serve.step", **step) as span:
+            out = (self._step_ragged() if self.mode == "ragged"
+                   else self._step_padded())
         s = self.scheduler
         self.obs.record_step(
-            out, dur_ms=(time.perf_counter() - t0) * 1e3,
+            out, dur_ms=span.seconds * 1e3,
             sched=s, kv=self.kv, cache=self.prefix_cache,
             table_pages=s._table_pages,
             trimmed_prefill=s.trimmed_prefill_step,
@@ -382,7 +386,8 @@ class EngineCore:
 
     def _step_padded(self) -> StepOutput:
         """The PR-3 right-aligned (lanes, C) block step (oracle mode)."""
-        plans, preempted = self.scheduler.schedule()
+        with self.obs.span("serve.schedule"):
+            plans, preempted = self.scheduler.schedule()
         return self._run_block(plans, preempted)
 
     def _step_ragged(self) -> StepOutput:
@@ -398,8 +403,9 @@ class EngineCore:
         equivalence oracle.  Token streams are identical either way.
         """
         s = self.scheduler
-        wants = s.begin_step()
-        batch, preempted = s.batch_for(wants)
+        with self.obs.span("serve.schedule"):
+            wants = s.begin_step()
+            batch, preempted = s.batch_for(wants)
         return self._run_stream(batch, preempted)
 
     def _run_block(self, plans, preempted) -> StepOutput:
@@ -409,27 +415,33 @@ class EngineCore:
                 tokens={}, finished=(), preempted=preempted, lanes=0,
                 prefill_tokens=0, decode_tokens=0,
                 prefix_hit_tokens=self.scheduler.prefix_hit_tokens_step)
+        obs = self.obs
         c = 1 if all(p.q_len == 1 for p in plans) else self.chunk_size
-        width = max(len(p.run.pages) for p in plans)
-        width = 1 << max(width - 1, 0).bit_length()    # retrace bucketing
         b, scratch = self.lanes, self.kv.scratch
-
-        toks = np.zeros((b, c), np.int32)
-        kv_len = np.zeros((b,), np.int32)
-        q_len = np.zeros((b,), np.int32)
-        tbl = np.full((b, width), scratch, np.int32)
-        for i, p in enumerate(plans):
-            toks[i, c - p.q_len:] = p.stream_tokens()
-            kv_len[i] = p.run.rows + p.q_len
-            q_len[i] = p.q_len
-            tbl[i, :len(p.run.pages)] = p.run.pages
-
-        logits, self.kv.pool = self._step(
-            self.params, self.kv.pool, jnp.asarray(tbl), jnp.asarray(toks),
-            jnp.asarray(kv_len), jnp.asarray(q_len))
-        return self._finish(plans, preempted, logits=logits,
-                            live=int(sum(p.q_len for p in plans)),
-                            padded=b * c)
+        with obs.span("serve.upload"):
+            width = max(len(p.run.pages) for p in plans)
+            width = 1 << max(width - 1, 0).bit_length()  # retrace bucketing
+            toks = np.zeros((b, c), np.int32)
+            kv_len = np.zeros((b,), np.int32)
+            q_len = np.zeros((b,), np.int32)
+            tbl = np.full((b, width), scratch, np.int32)
+            for i, p in enumerate(plans):
+                toks[i, c - p.q_len:] = p.stream_tokens()
+                kv_len[i] = p.run.rows + p.q_len
+                q_len[i] = p.q_len
+                tbl[i, :len(p.run.pages)] = p.run.pages
+            args = (jnp.asarray(tbl), jnp.asarray(toks), jnp.asarray(kv_len),
+                    jnp.asarray(q_len))
+        with obs.span("serve.dispatch"):
+            logits, self.kv.pool = self._step(self.params, self.kv.pool,
+                                              *args)
+        with obs.span("serve.wait"):
+            logits = np.asarray(logits)
+        with obs.span("serve.commit"):
+            del args                # frees the step's input buffers here
+            return self._finish(plans, preempted, logits=logits,
+                                live=int(sum(p.q_len for p in plans)),
+                                padded=b * c)
 
     def _run_stream(self, batch, preempted) -> StepOutput:
         """Execute a RaggedBatch as one packed token stream."""
@@ -439,45 +451,52 @@ class EngineCore:
                 tokens={}, finished=(), preempted=preempted, lanes=0,
                 prefill_tokens=0, decode_tokens=0,
                 prefix_hit_tokens=self.scheduler.prefix_hit_tokens_step)
-        # Stream index of each plan's final token; idle tail lanes point at
-        # row 0 (their logits are computed but never read — the (lanes, V)
-        # output shape stays static across schedules).  Speculative engines
-        # always pass the (lanes, 1 + spec_k) form — row j of lane i is the
-        # lane's decode row plus its j-th drafted row, clamped to the last
-        # real draft — so the verify extraction is one static-shape gather:
-        # k stays a compile-time constant and trace count stays O(1)
-        # whether a step carries 0 or k drafts.
-        if self.speculative:
-            last_idx = np.zeros((self.lanes, self.spec_k + 1), np.int32)
-            ramp = np.arange(self.spec_k + 1, dtype=np.int32)
-            for i, p in enumerate(plans):
-                d = len(p.drafts)
-                base = int(batch.cu_seqlens[i + 1]) - 1 - d
-                last_idx[i] = base + np.minimum(ramp, d)
-        else:
-            last_idx = np.zeros((self.lanes,), np.int32)
-            last_idx[:len(plans)] = batch.cu_seqlens[1:] - 1
+        obs = self.obs
+        with obs.span("serve.upload"):
+            # Stream index of each plan's final token; idle tail lanes
+            # point at row 0 (their logits are computed but never read —
+            # the (lanes, V) output shape stays static across schedules).
+            # Speculative engines always pass the (lanes, 1 + spec_k) form
+            # — row j of lane i is the lane's decode row plus its j-th
+            # drafted row, clamped to the last real draft — so the verify
+            # extraction is one static-shape gather: k stays a
+            # compile-time constant and trace count stays O(1) whether a
+            # step carries 0 or k drafts.
+            if self.speculative:
+                last_idx = np.zeros((self.lanes, self.spec_k + 1), np.int32)
+                ramp = np.arange(self.spec_k + 1, dtype=np.int32)
+                for i, p in enumerate(plans):
+                    d = len(p.drafts)
+                    base = int(batch.cu_seqlens[i + 1]) - 1 - d
+                    last_idx[i] = base + np.minimum(ramp, d)
+            else:
+                last_idx = np.zeros((self.lanes,), np.int32)
+                last_idx[:len(plans)] = batch.cu_seqlens[1:] - 1
 
-        # Lane boundaries as a compute input, static (lanes + 2,) shape:
-        # the live plans' boundaries, then the bucket's dead padding rows
-        # as one trailing pseudo-segment ending at T (so cu[-1] == T — the
-        # kernel's validated packing contract), then zero-width repeats.
-        cu = np.full((self.lanes + 2,), batch.width, np.int32)
-        cu[:len(batch.cu_seqlens)] = batch.cu_seqlens
-
-        picks, self.kv.pool = self._ragged(
-            self.params, self.kv.pool, jnp.asarray(batch.table),
-            jnp.asarray(batch.tokens), jnp.asarray(batch.pos),
-            jnp.asarray(last_idx), jnp.asarray(cu),
-            *self._sampling_inputs(plans))
-        picks = np.asarray(picks)
-        bad = [p.run.req.uid for p, row in zip(plans, picks)
-               if np.any(row == NONFINITE_PICK)]
-        if bad:
-            raise FloatingPointError(
-                f"non-finite logits in this step for requests {bad}")
-        return self._finish(plans, preempted, picks=picks,
-                            live=batch.live, padded=batch.width)
+            # Lane boundaries as a compute input, static (lanes + 2,)
+            # shape: the live plans' boundaries, then the bucket's dead
+            # padding rows as one trailing pseudo-segment ending at T (so
+            # cu[-1] == T — the kernel's validated packing contract), then
+            # zero-width repeats.
+            cu = np.full((self.lanes + 2,), batch.width, np.int32)
+            cu[:len(batch.cu_seqlens)] = batch.cu_seqlens
+            args = (jnp.asarray(batch.table), jnp.asarray(batch.tokens),
+                    jnp.asarray(batch.pos), jnp.asarray(last_idx),
+                    jnp.asarray(cu), *self._sampling_inputs(plans))
+        with obs.span("serve.dispatch"):
+            picks, self.kv.pool = self._ragged(self.params, self.kv.pool,
+                                               *args)
+        with obs.span("serve.wait"):
+            picks = np.asarray(picks)
+            bad = [p.run.req.uid for p, row in zip(plans, picks)
+                   if np.any(row == NONFINITE_PICK)]
+            if bad:
+                raise FloatingPointError(
+                    f"non-finite logits in this step for requests {bad}")
+        with obs.span("serve.commit"):
+            del args                # frees the step's input buffers here
+            return self._finish(plans, preempted, picks=picks,
+                                live=batch.live, padded=batch.width)
 
     def _sampling_inputs(self, plans):
         """Per-lane sampling arrays for the in-step draw, all (lanes,).

@@ -39,6 +39,10 @@ This module is that process, as one serve loop and one async generator:
   work finish; ``drain=False`` aborts every in-flight client first.  The
   async context manager form does a draining shutdown on exit.
 
+The loop's work between steps is spanned (``serve.intake``,
+``serve.flush``), and while it runs the GC and compile stall hooks of
+``ServingObservability`` are installed.
+
 Latency telemetry (TTFT / TPOT / sustained req/s) flows into the engine's
 metrics registry (``serving/tracing.py``); :meth:`AsyncLMServer.summary`
 is a thin window over it — the nightly serve-loop bench, the ``/metrics``
@@ -252,10 +256,12 @@ class AsyncLMServer:
                 del self._clients[uid]
 
     async def _serve(self) -> None:
+        self.obs.install_hooks()
         try:
             while True:
-                self._drain_intake()
-                self._process_aborts()
+                with self.obs.span("serve.intake"):
+                    self._drain_intake()
+                    self._process_aborts()
                 if not self.engine.scheduler.has_work():
                     if (self._closing and self._intake.empty()
                             and not self._aborts):
@@ -271,12 +277,15 @@ class AsyncLMServer:
                 # toucher, so submit/abort/step are serialized for free.
                 await asyncio.to_thread(self.engine.step)
                 self.steps += 1
-                self._flush()
+                with self.obs.span("serve.flush"):
+                    self._flush()
         except BaseException as e:
             for client in self._clients.values():
                 client.queue.put_nowait(e)
             self._clients.clear()
             raise
+        finally:
+            self.obs.remove_hooks()
 
     # ------------------------------------------------------------ telemetry
     def summary(self) -> dict:

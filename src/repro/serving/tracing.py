@@ -21,26 +21,105 @@ n-gram proposer, AsyncLMServer).  It owns
   PR 8 class of bug (a mid-traffic table-width shrink forcing a ~2 s
   XLA stall) is a metric, not an archaeology project.
 
+It also opens the stack's **phase spans** (:meth:`ServingObservability.span`):
+each is a ``jax.profiler.TraceAnnotation`` — on the device trace's own
+clock, so a profiler window shows which phase of the step or the serve
+loop the host was in during every device idle gap — and its host-clock
+seconds accumulate in a ``<phase>_seconds_total`` counter.  Two more
+hooks feed stall counters: Python GC pauses (span ``serve.gc``) and JAX
+compiles and compile-cache loads (a ``jax.monitoring`` listener).
+
 Every hook early-returns when ``enabled=False`` (metrics-off engines
-for the overhead A/B) and everything stays host-side, off the jitted
-path.
+for the overhead A/B), a span then only opens its annotation, and
+everything stays host-side, off the jitted path.
 """
 from __future__ import annotations
 
+import gc
+import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-from .metrics import Histogram, MetricsRegistry
+import jax
+from jax.profiler import TraceAnnotation
+
+from .metrics import Counter, Histogram, MetricsRegistry
 
 __all__ = [
+    "PHASE_SPANS",
+    "PhaseSpan",
     "SpanEvent",
     "RequestSpan",
     "RequestTracer",
     "StepTraceRing",
     "ServingObservability",
 ]
+
+
+# ------------------------------------------------------- phase spans --
+
+# The stack's profiler spans and the counter each one's host-clock seconds
+# go to.  ``serve.step`` is one ``EngineCore.step``; the five phases after
+# it tile it in order; ``serve.intake`` and ``serve.flush`` are the serve
+# loop's work between steps; ``serve.gc`` is a Python GC pause.
+PHASE_SPANS: Dict[str, Tuple[str, str]] = {
+    "serve.step": ("step_seconds_total", "seconds in EngineCore.step()"),
+    "serve.schedule": ("schedule_seconds_total",
+                       "seconds admitting, planning and packing a step"),
+    "serve.upload": ("upload_seconds_total",
+                     "seconds building and uploading a step's inputs"),
+    "serve.dispatch": ("dispatch_seconds_total",
+                       "seconds enqueueing the step (traces and compiles "
+                       "on a jit-cache miss)"),
+    "serve.wait": ("wait_seconds_total",
+                   "seconds the host waited for the step's tokens"),
+    "serve.commit": ("commit_seconds_total",
+                     "seconds committing tokens and retiring requests"),
+    "serve.intake": ("intake_seconds_total",
+                     "seconds the serve loop spent on intake and aborts"),
+    "serve.flush": ("flush_seconds_total",
+                    "seconds the serve loop spent streaming tokens out"),
+    "serve.gc": ("gc_pause_seconds_total", "seconds in Python GC pauses"),
+}
+
+# jax.monitoring events: what JAX spends tracing, lowering and compiling
+# (a compile-cache load is timed as a compile), and a cache load itself.
+_COMPILE_EVENTS = frozenset((
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+))
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class PhaseSpan:
+    """One host span: a profiler annotation around the block, and its
+    host-clock duration added to ``counter`` (``None``: annotation only).
+    ``seconds`` holds the duration once the block has exited."""
+
+    __slots__ = ("name", "attrs", "counter", "seconds", "_t0", "_ann")
+
+    def __init__(self, name: str, counter: Optional[Counter], **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.counter = counter
+        self.seconds = 0.0
+
+    def __enter__(self) -> "PhaseSpan":
+        self._ann = TraceAnnotation(self.name, **self.attrs)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        # The bookkeeping runs inside the annotation, so that consecutive
+        # spans leave the least time between them.
+        self.seconds = time.perf_counter() - self._t0
+        if self.counter is not None:
+            self.counter.inc(self.seconds)
+        self._ann.__exit__(*exc)
 
 
 # ------------------------------------------------------------- spans --
@@ -167,8 +246,21 @@ class ServingObservability:
         self.ring = StepTraceRing(ring_capacity)
         self.warm = False
         self._profiler: Optional[dict] = None
+        self._hooked = False
+        self._gc_span: Optional[PhaseSpan] = None
+        self._compile_lock = threading.Lock()
 
         r = self.registry
+        self._phase_counters = {
+            name: r.counter(counter, help)
+            for name, (counter, help) in PHASE_SPANS.items()}
+        self.c_jit_compile_s = r.counter(
+            "jit_compile_seconds_total",
+            "seconds JAX spent tracing, lowering and compiling (or loading "
+            "from the compile cache)")
+        self.c_cache_loads = r.counter(
+            "compile_cache_loads_total",
+            "executables loaded from the persistent compile cache")
         # -- step/engine counters
         self.c_steps = r.counter(
             "steps_total", "engine steps executed")
@@ -276,6 +368,57 @@ class ServingObservability:
             "stream_ttft_ms", "server submit to first streamed token")
         self.h_stream_tpot_ms = r.histogram(
             "stream_tpot_ms", "server mean inter-token time per stream")
+
+    # ----------------------------------------------------- phase spans --
+    def span(self, name: str, **attrs) -> PhaseSpan:
+        """``with obs.span("serve.schedule"):`` — a profiler annotation
+        named ``name`` (one of :data:`PHASE_SPANS`; ``attrs`` become its
+        trace stats), whose host seconds go to the phase's counter when
+        enabled."""
+        counter = self._phase_counters[name]
+        return PhaseSpan(name, counter if self.enabled else None, **attrs)
+
+    def install_hooks(self) -> None:
+        """Start the stall hooks: a ``gc.callbacks`` entry that spans each
+        GC pause as ``serve.gc``, and a ``jax.monitoring`` listener for
+        ``jit_compile_seconds_total`` / ``compile_cache_loads_total``.
+        Both are process-wide, so the owner removes them with
+        :meth:`remove_hooks` (the serve loop does on exit)."""
+        if self._hooked:
+            return
+        self._hooked = True
+        gc.callbacks.append(self._on_gc)
+        jax.monitoring.register_event_duration_secs_listener(self._on_compile)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def remove_hooks(self) -> None:
+        if not self._hooked:
+            return
+        self._hooked = False
+        gc.callbacks.remove(self._on_gc)
+        jax.monitoring.unregister_event_duration_listener(self._on_compile)
+        jax.monitoring.unregister_event_listener(self._on_event)
+        if self._gc_span is not None:
+            self._gc_span.__exit__(None, None, None)
+            self._gc_span = None
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # A collection starts and stops on one thread, under the GIL.
+        if phase == "start":
+            self._gc_span = self.span("serve.gc").__enter__()
+        elif self._gc_span is not None:
+            self._gc_span.__exit__(None, None, None)
+            self._gc_span = None
+
+    def _on_compile(self, event: str, secs: float, **_) -> None:
+        if self.enabled and event in _COMPILE_EVENTS:
+            with self._compile_lock:     # compiles may run on any thread
+                self.c_jit_compile_s.inc(secs)
+
+    def _on_event(self, event: str, **_) -> None:
+        if self.enabled and event == _CACHE_LOAD_EVENT:
+            with self._compile_lock:
+                self.c_cache_loads.inc()
 
     # ------------------------------------------------- retrace sentinel --
     def step_traced(self) -> None:
@@ -473,20 +616,6 @@ class ServingObservability:
             self._profiler = None
 
     # ------------------------------------------------- summary windows --
-    def engine_window(self) -> Dict[str, int]:
-        """Anchor for a per-pass latency window over the engine-side
-        TTFT/TPOT histograms (bench batch arms)."""
-        return {"ttft_n": self.h_ttft_ms.count(),
-                "tpot_n": self.h_tpot_ms.count()}
-
-    def engine_latency_summary(self, window: Dict[str, int]) -> Dict[str, float]:
-        skip_t, skip_p = window["ttft_n"], window["tpot_n"]
-        return {
-            "ttft_ms_p50": self.h_ttft_ms.percentile(0.50, skip=skip_t),
-            "ttft_ms_p99": self.h_ttft_ms.percentile(0.99, skip=skip_t),
-            "tpot_ms": self.h_tpot_ms.mean(skip=skip_p),
-        }
-
     def server_window(self) -> Dict[str, float]:
         """Anchor for a per-server-instance summary window."""
         return {"requests": self.c_stream_requests.value(),
@@ -533,21 +662,4 @@ class ServingObservability:
                 0.99, skip=int(w["ttft_n"])),
             "tpot_ms": self.h_stream_tpot_ms.mean(skip=int(w["tpot_n"])),
             "tokens": int(self.c_stream_tokens.value() - w["tokens"]),
-        }
-
-    def spec_window(self) -> Dict[str, dict]:
-        return self.registry.snapshot()
-
-    def spec_summary(self, since: Dict[str, dict]) -> Dict[str, float]:
-        d = self.registry.delta(since)
-        drafted = d.get("spec_drafted_tokens_total", 0)
-        accepted = d.get("spec_accepted_tokens_total", 0)
-        spec_steps = d.get("spec_steps_total", 0)
-        return {
-            "drafted_tokens": int(drafted),
-            "accepted_tokens": int(accepted),
-            "spec_steps": int(spec_steps),
-            "acceptance": accepted / drafted if drafted else 0.0,
-            "accepted_per_spec_step":
-                accepted / spec_steps if spec_steps else 0.0,
         }

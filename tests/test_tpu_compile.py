@@ -7,6 +7,7 @@ slices of values, lane-flattening reshapes).  Shapes are deepseek-7b's:
 32 KV heads of 128, pages of 16 rows, q-blocks of 32.
 """
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.lut_exp import make_table
 from repro.kernels.lut_exp.kernel import lut_exp_2d
 from repro.kernels.paged_attention import paged_attention_varlen
+from repro.kernels.paged_attention.kernel import KERNEL_NAME
 from repro.kernels.streaming_attention.kernel import attention_3d
 
 ops = importlib.import_module("repro.kernels.paged_attention.ops")
@@ -63,6 +65,31 @@ def test_varlen_paged_kernel_compiles(one_chip, monkeypatch, kv, exp_mode):
                          scale, scale, sds((t, p), jnp.int32),
                          sds((t,), jnp.int32), sds((lanes + 1,), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+def test_varlen_kernel_is_named(one_chip, monkeypatch):
+    # A trace finds the kernel's device ops by the substring
+    # "paged_attention" of their names: the kernel's custom call carries
+    # it, and no other instruction of the step does.
+    monkeypatch.setattr(ops, "_use_kernel", lambda: True)
+    t, n, p, lanes = 64, 64, 8, 4
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((n, H, PS, D), jnp.bfloat16)
+    hlo = _compiled_text(
+        lambda q, kp, vp, pages, pos, cu: paged_attention_varlen(
+            q, kp, vp, pages, pos, cu_seqlens=cu, block_q=BQ,
+            interpret=False),
+        sds((t, H, D), jnp.bfloat16), pool, pool, sds((t, p), jnp.int32),
+        sds((t,), jnp.int32), sds((lanes + 1,), jnp.int32))
+    named = [line for line in hlo.splitlines()
+             if re.match(r"\s*(ROOT )?%\S*paged_attention", line)]
+    assert named and all(
+        re.match(rf"\s*(ROOT )?%{KERNEL_NAME}(\.\d+)? = ", line)
+        and 'custom_call_target="tpu_custom_call"' in line
+        for line in named), named
 
 
 def test_streaming_kernel_compiles_at_block_512(one_chip):
