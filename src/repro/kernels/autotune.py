@@ -40,7 +40,9 @@ Workload = Sequence[Tuple[int, int]]
 class KernelConfig:
     """Block shapes of the varlen paged-attention kernel (static facts)."""
     block_q: int = 8            # q-block rows; 1 = untiled batch=T dataflow
-    block_pages: Optional[int] = None   # pages per scan step (None = auto)
+    # pages per jnp-scan step and per TPU-kernel grid step (clamped to the
+    # table width); None = 128 rows' worth
+    block_pages: Optional[int] = None
     dequant: str = "block"      # int8 scale granularity: "block" | "page"
     source: str = "default"     # "default" | "tuned" — provenance
 

@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.lut_exp import make_table
-from repro.kernels.paged_attention.ref import paged_attention_reference
+from repro.kernels.paged_attention.ref import (default_block_pages,
+                                               paged_attention_reference)
 
 
 def _use_kernel() -> bool:
@@ -66,14 +67,15 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             f"{jax.default_backend()!r}); pass interpret=True for interpret "
             "mode or interpret=None for the platform default")
 
-    # The Pallas kernel walks one page per grid step, so its dequant is
-    # inherently per-page; the `dequant` knob only shapes the jnp scan.
+    # The Pallas kernel walks ``block_pages`` pages per grid step and
+    # dequantises per KV row; the `dequant` knob only shapes the jnp scan.
     from repro.kernels.paged_attention.kernel import paged_attention_4d
     g = hq // hkv
     out = paged_attention_4d(
         q.reshape(b, hkv, g * lq, d), k_pool, v_pool, k_scale, v_scale,
         page_table, kv_len, make_table(), scale=float(scale), cap=cap,
         window=window, exp_mode=exp_mode, group=g, q_len=lq,
+        block_pages=block_pages or default_block_pages(k_pool.shape[2]),
         interpret=bool(interpret) if interpret is not None
         else not _use_kernel())
     return out.reshape(b, hq, lq, v_pool.shape[-1])

@@ -31,7 +31,7 @@ its page-table row is the lane's row, its ``kv_len`` is
 ``q_pos[start] + Bq`` so kernel row ``i`` sits at position
 ``q_pos[start] + i`` — exactly the packed positions, because serving packs
 each lane's chunk rows at contiguous ascending positions.  The grid becomes
-``(q_block, kv_head, page_slot)`` and each KV page is read **once per
+``(q_block, kv_head, page_block)`` and each KV page is read **once per
 q-block instead of once per token** — ~Bq× less KV traffic on prefill
 chunks.  Outputs scatter back to stream order through a token→slot map.
 Block shapes (``block_q``, ``block_pages``, dequant granularity) are picked
@@ -57,7 +57,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.paged_attention.ops import paged_attention
-from repro.kernels.paged_attention.ref import paged_attention_reference
+from repro.kernels.paged_attention.ref import (default_block_pages,
+                                               paged_attention_reference)
 
 
 def varlen_positions(cu_seqlens, seq_lens) -> np.ndarray:
@@ -151,6 +152,38 @@ def q_block_layout(cu: jax.Array, q_pos: jax.Array, t: int, bq: int
     return rows, start, kv_len, slot
 
 
+def kv_block_counts(cu_seqlens, q_pos, t: int, *, block_q: int,
+                    page_size: int, table_width: int,
+                    block_pages: Optional[int]) -> Tuple[int, int]:
+    """``(walked, live)`` (q_block, page_block) pairs of one kernel call,
+    counted on the host in O(blocks).
+
+    ``walked`` is the grid's ``NB × ceil(P / bp)``; ``live`` counts the
+    pairs holding a row before the block's ``kv_len`` (cut at the table's
+    end) — the pairs the kernel copies and computes.  Mirrors
+    :func:`paged_attention_varlen`'s dispatch (``Bq = min(block_q, T)``),
+    :func:`q_block_layout`'s ``kv_len`` and the kernel's
+    ``bp = min(block_pages, P)``.
+    """
+    pos = np.asarray(q_pos, np.int64)
+    bq = min(block_q, max(t, 1))
+    if bq > 1:
+        cu = np.asarray(cu_seqlens, np.int64)
+        nbi = -(-np.diff(cu) // bq)                  # q-blocks per lane
+        first = np.repeat(np.cumsum(nbi) - nbi, nbi)
+        starts = np.repeat(cu[:-1], nbi) + bq * (
+            np.arange(int(nbi.sum())) - first)
+        dead = t // bq + len(nbi) - len(starts)      # pinned to kv_len 1
+        kv = np.concatenate([pos[starts] + bq, np.ones(dead, np.int64)])
+    else:                                            # one block per token
+        kv = pos[:t] + 1
+    bp = min(block_pages or default_block_pages(page_size), table_width)
+    nblk = -(-table_width // bp)
+    kv = np.minimum(kv, table_width * page_size)
+    live = np.minimum(-(-kv // (bp * page_size)), nblk)
+    return len(kv) * nblk, int(live.sum())
+
+
 def _as_4d(q: jax.Array) -> jax.Array:
     t, hq, d = q.shape
     return q.reshape(t, hq, 1, d)
@@ -190,7 +223,7 @@ def paged_attention_varlen(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     q_pos: (T,) per-token absolute position / causal bound.
 
     ``block_q = Bq > 1`` with ``cu_seqlens`` selects the q-block-tiled
-    dataflow (module docstring): grid ``(q_block, kv_head, page_slot)``,
+    dataflow (module docstring): grid ``(q_block, kv_head, page_block)``,
     each KV page read once per block instead of once per token.  Tiling
     additionally requires each lane's packed rows to sit at contiguous
     ascending positions (``q_pos[i+1] = q_pos[i] + 1`` within a lane) —

@@ -230,7 +230,7 @@ PLATFORMS: Dict[str, PlatformSpec] = {
     "cpu": PlatformSpec("cpu", mem_bw_gbs=40.0, flops=2e11,
                         dispatch_ns=400.0, dequant_page_ns=200.0),
     # one TPU core running the Pallas scalar-prefetch kernel; per-page
-    # dequant is free there (the kernel walks one page per step anyway)
+    # dequant is free there (the kernel dequantises each KV row in place)
     "tpu": PlatformSpec("tpu", mem_bw_gbs=1.2e3, flops=2e14,
                         dispatch_ns=120.0, dequant_page_ns=0.0),
 }

@@ -72,6 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels.paged_attention.varlen import kv_block_counts
 from repro.models import build_model
 from repro.serving.api import (Request, RequestState, StepOutput,
                                UnsupportedCacheLayout)
@@ -480,6 +481,13 @@ class EngineCore:
             # zero-width repeats.
             cu = np.full((self.lanes + 2,), batch.width, np.int32)
             cu[:len(batch.cu_seqlens)] = batch.cu_seqlens
+            if obs.enabled:
+                kc = self.kernel_config
+                obs.kv_blocks(*kv_block_counts(
+                    cu, batch.pos, batch.width, block_q=kc.block_q,
+                    page_size=self.kv.page_size,
+                    table_width=batch.table.shape[1],
+                    block_pages=kc.block_pages))
             args = (jnp.asarray(batch.table), jnp.asarray(batch.tokens),
                     jnp.asarray(batch.pos), jnp.asarray(last_idx),
                     jnp.asarray(cu), *self._sampling_inputs(plans))

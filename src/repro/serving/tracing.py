@@ -280,6 +280,14 @@ class ServingObservability:
             "padded_rows_total", "padded stream width summed over steps")
         self.c_tokens_out = r.counter(
             "tokens_generated_total", "tokens committed to requests")
+        # Host mirror of the varlen attention kernel's grid, per layer call
+        # (kernels/paged_attention/varlen.py:kv_block_counts).
+        self.c_kv_blocks_walked = r.counter(
+            "attn_kv_blocks_walked_total",
+            "(q_block, page_block) pairs the attention grid walks")
+        self.c_kv_blocks_live = r.counter(
+            "attn_kv_blocks_live_total",
+            "walked pairs holding a live KV row (copied and computed)")
         self.c_trim_prefill = r.counter(
             "trimmed_prefill_tokens_total",
             "prefill tokens deferred by bucket trimming")
@@ -527,6 +535,11 @@ class ServingObservability:
         if not self.enabled:
             return
         self.c_prefix_evicted.inc(pages)
+
+    def kv_blocks(self, walked: int, live: int) -> None:
+        """One ragged step's attention walk: grid pairs and live pairs."""
+        self.c_kv_blocks_walked.inc(walked)
+        self.c_kv_blocks_live.inc(live)
 
     # ------------------------------------------------------- step hook --
     def record_step(self, out, *, dur_ms: float, sched, kv,

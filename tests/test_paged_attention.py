@@ -77,10 +77,13 @@ def test_paged_matches_contiguous_backends(group, b, ps, p, seed):
                                    err_msg=backend)
 
 
+@pytest.mark.parametrize("block_pages", [1, 2, 8])
 @settings(max_examples=8, deadline=None)
 @given(st.integers(1, 3), st.sampled_from([4, 8]), st.integers(0, 10_000))
-def test_paged_kernel_interpret_matches_reference(group, ps, seed):
-    """The Pallas kernel (interpret mode) == the jnp page-block reference."""
+def test_paged_kernel_interpret_matches_reference(block_pages, group, ps,
+                                                  seed):
+    """The Pallas kernel (interpret mode) == the jnp page-block reference,
+    walking ``block_pages`` pages per grid step (8 > the table width)."""
     rng = np.random.default_rng(seed)
     b, hkv, d, p = 2, 2, 16, 3
     n = p * b + 2
@@ -91,7 +94,7 @@ def test_paged_kernel_interpret_matches_reference(group, ps, seed):
 
     ref = paged_attention_reference(q, kp, vp, tbl, lens, exp_mode="lut")
     ker = paged_attention(q, kp, vp, tbl, lens, exp_mode="lut",
-                          interpret=True)
+                          block_pages=block_pages, interpret=True)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                atol=2e-5, rtol=1e-4)
 
@@ -197,11 +200,14 @@ def test_chunked_prefill_matches_contiguous_backends(group, lq, ps, seed):
                                    err_msg=backend)
 
 
+@pytest.mark.parametrize("block_pages", [1, 2, 8])
 @settings(max_examples=6, deadline=None)
 @given(st.integers(1, 3), st.integers(2, 5), st.integers(0, 10_000))
-def test_chunked_kernel_interpret_matches_reference(group, lq, seed):
+def test_chunked_kernel_interpret_matches_reference(block_pages, group, lq,
+                                                    seed):
     """The Pallas kernel (interpret mode) == the jnp reference for
-    multi-row chunks — the per-row causal bound lives in both."""
+    multi-row chunks — the per-row causal bound lives in both, whatever
+    the page block."""
     rng = np.random.default_rng(seed)
     b, hkv, d, ps, p = 2, 2, 16, 8, 3
     n = p * b + 2
@@ -213,7 +219,7 @@ def test_chunked_kernel_interpret_matches_reference(group, lq, seed):
 
     ref = paged_attention_reference(q, kp, vp, tbl, lens, exp_mode="lut")
     ker = paged_attention(q, kp, vp, tbl, lens, exp_mode="lut",
-                          interpret=True)
+                          block_pages=block_pages, interpret=True)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                atol=2e-5, rtol=1e-4)
 

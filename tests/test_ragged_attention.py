@@ -133,11 +133,13 @@ def test_varlen_matches_padded_paged_oracle(group, lanes, ps, seed):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-4)
 
 
+@pytest.mark.parametrize("block_pages", [1, 2, 8])
 @settings(max_examples=8, deadline=None)
 @given(st.integers(1, 3), st.sampled_from([4, 8]), st.integers(0, 10_000))
-def test_varlen_kernel_interpret_matches_reference(group, ps, seed):
+def test_varlen_kernel_interpret_matches_reference(block_pages, group, ps,
+                                                   seed):
     """The Pallas kernel (interpret mode, grid over tokens) == the jnp
-    varlen reference."""
+    varlen reference, at every page block."""
     rng = np.random.default_rng(seed)
     lanes, hkv, d, p = 3, 2, 16, 3
     n = p * lanes + 1
@@ -148,7 +150,8 @@ def test_varlen_kernel_interpret_matches_reference(group, ps, seed):
     ref = paged_attention_varlen_reference(q, kp, vp, token_tbl, q_pos,
                                            exp_mode="lut")
     ker = paged_attention_varlen(q, kp, vp, token_tbl, q_pos,
-                                 exp_mode="lut", interpret=True)
+                                 exp_mode="lut", block_pages=block_pages,
+                                 interpret=True)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                atol=2e-5, rtol=1e-4)
 
@@ -301,9 +304,10 @@ def test_tiled_matches_contiguous_oracle(group, lanes, block_q, seed):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-4)
 
 
-def test_tiled_kernel_interpret_matches_reference(rng):
+@pytest.mark.parametrize("block_pages", [1, 2, 8])
+def test_tiled_kernel_interpret_matches_reference(rng, block_pages):
     """The Pallas kernel under q-block tiling (grid (q_block, kv_head,
-    page_slot), interpret mode) == the untiled jnp reference."""
+    page_block), interpret mode) == the untiled jnp reference."""
     hq, hkv, d, ps, p = 4, 2, 16, 8, 3
     n = 16
     kp, vp = make_pool(rng, n, hkv, ps, d)
@@ -312,7 +316,8 @@ def test_tiled_kernel_interpret_matches_reference(rng):
 
     ref = paged_attention_varlen_reference(q, kp, vp, token_tbl, q_pos)
     ker = paged_attention_varlen(q, kp, vp, token_tbl, q_pos,
-                                 cu_seqlens=cu, block_q=8, interpret=True)
+                                 cu_seqlens=cu, block_q=8,
+                                 block_pages=block_pages, interpret=True)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                atol=2e-5, rtol=1e-4)
 

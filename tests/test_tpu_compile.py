@@ -67,6 +67,44 @@ def test_varlen_paged_kernel_compiles(one_chip, monkeypatch, kv, exp_mode):
     assert "tpu_custom_call" in hlo
 
 
+# (Hkv, G, Lq, table width P, q-blocks NB) of the two benchmark cells'
+# steps: deepseek-7b decode (rows 16) and with a prefill chunk (rows 32),
+# starcoder2-3b's mixed step (GQA 24:2, rows 12·32 = 384).
+CELL_SHAPES = {
+    "ds7b-rows16": (32, 1, 16, 128, 18),
+    "ds7b-rows32": (32, 1, 32, 128, 18),
+    "sc2-rows384": (2, 12, 32, 256, 50),
+}
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("cell", list(CELL_SHAPES))
+@pytest.mark.parametrize("block_pages", [8, 16])
+def test_page_block_kernel_compiles_at_cell_widths(one_chip, monkeypatch,
+                                                   block_pages, cell, kv):
+    # 16-row pages, 8 (128 rows) or 16 (the TPU row of the kernel table)
+    # per grid step.
+    monkeypatch.setattr(ops, "_use_kernel", lambda: True)
+    hkv, g, lq, p, nb = CELL_SHAPES[cell]
+    n = 512
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((n, hkv, PS, D), jnp.int8 if kv == "int8" else jnp.bfloat16)
+    scale = sds((n, hkv, PS), jnp.float32) if kv == "int8" else None
+
+    def attend(q, kp, vp, ks, vs, tbl, lens):
+        return ops.paged_attention(q, kp, vp, tbl, lens, k_scale=ks,
+                                   v_scale=vs, block_pages=block_pages,
+                                   interpret=False)
+
+    hlo = _compiled_text(attend, sds((nb, hkv * g, lq, D), jnp.bfloat16),
+                         pool, pool, scale, scale, sds((nb, p), jnp.int32),
+                         sds((nb,), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
 def test_varlen_kernel_is_named(one_chip, monkeypatch):
     # A trace finds the kernel's device ops by the substring
     # "paged_attention" of their names: the kernel's custom call carries
